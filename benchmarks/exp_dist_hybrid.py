@@ -15,9 +15,11 @@ Per row we report count, pull bytes (fetch-stage remote vids × (D_pad+2)·4),
 push bytes (join-shuffle rows crossing shards × row width), steal bytes, and
 the Eq.-3 prediction from hybrid_comm.enum_join_mode for context.
 
-XLA fixes the host device count at import, so the measurement runs in a
-fresh interpreter with ``--xla_force_host_platform_device_count=8`` (same
-mechanism as tests/test_distributed.py); invoke via
+On the CPU, XLA fixes the host device count at import, so the measurement
+runs in a fresh interpreter with ``--xla_force_host_platform_device_count=8``
+(same mechanism as tests/test_distributed.py). On an accelerator the process
+that asks for the backend holds the chips, so the measurement runs in that
+same process over every device it sees. Invoke via
 ``PYTHONPATH=src python -m benchmarks.run exp_dist_hybrid`` (EXPERIMENTS.md
 §Distributed-hybrid).
 """
@@ -32,7 +34,7 @@ QUERIES = ("q1", "q2")  # q7+ explode at CI scale; run them via launch/enumerate
 SYSTEMS = (("pull-only", "benu"), ("push-only", "seed"), ("hybrid", "huge"))
 
 
-def inner() -> None:
+def inner(shards: int = SHARDS) -> None:
     import time
 
     import jax
@@ -44,7 +46,9 @@ def inner() -> None:
     from repro.core.hybrid_comm import enum_join_mode
     from repro.graph import powerlaw_graph
 
-    mesh = jax.make_mesh((SHARDS,), ("shards",))
+    from repro.launch.mesh import auto_mesh
+
+    mesh = auto_mesh((shards,), ("shards",))
     graph = powerlaw_graph(1 << 9, 6.0, seed=7)
     stats = GraphStats.from_graph(graph)
     engines = {
@@ -90,7 +94,7 @@ def inner() -> None:
         dec = enum_join_mode(
             left_rows=max(hybrid_count, 1), right_rows=max(hybrid_count, 1),
             width_left=q.num_vertices, width_right=q.num_vertices,
-            graph_edges=stats.num_directed_edges / 2, machines=SHARDS,
+            graph_edges=stats.num_directed_edges / 2, machines=shards,
         )
         emit(
             f"exp_dist_hybrid/eq3/{qname}", 0.0,
@@ -102,7 +106,13 @@ def inner() -> None:
 
 
 def main() -> None:
-    """Relay the measurement from a fresh interpreter with 8 host devices."""
+    """Run in process on an accelerator; on the CPU, relay the measurement
+    from a fresh interpreter with 8 host devices."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        inner(len(jax.devices()))
+        return
     env = dict(
         os.environ,
         PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH", ""),

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.analysis.flowcheck import verify_flow
 from repro.core import operators as ops_mod
@@ -68,7 +68,7 @@ class DistConfig:
     batch_size: int = 256
     queue_capacity: int = 1 << 16
     join_buffer_capacity: int = 1 << 17  # rows per join side buffer per shard
-    join_out_capacity: int = 1 << 17     # worst-case rows per probe step
+    join_out_capacity: int = 1 << 17     # most rows one probe step emits
     axis: str = "shards"
     rebalance: bool = True               # inter-machine work stealing
     fused: bool = False                  # fused extend/verify + probe kernels
@@ -93,10 +93,10 @@ class _DQueue:
         cap = eng.cfg.queue_capacity if capacity is None else capacity
         self.capacity = cap + slack  # physical rows, engine.DeviceQueue-style
         self.width = width
-        self.buf = jax.device_put(
-            jnp.full((eng.p, cap + slack, width), INVALID, jnp.int32), eng.sh(3)
+        self.buf = jnp.full(
+            (eng.p, cap + slack, width), INVALID, jnp.int32, device=eng.sh(3)
         )
-        self.n = jax.device_put(jnp.zeros((eng.p,), jnp.int32), eng.sh(1))
+        self.n = jnp.zeros((eng.p,), jnp.int32, device=eng.sh(1))
         self._eng = eng
         self._max = 0
         self._dirty = False
@@ -108,6 +108,13 @@ class _DQueue:
     def set_n(self, n: jax.Array) -> None:
         self.n = n
         self._dirty = True
+
+    def popped(self, n: jax.Array, k: int) -> None:
+        """``n`` after every shard popped up to ``k`` rows: the new maximum
+        follows from the cached one without a read from the device."""
+        self.n = n
+        if not self._dirty:
+            self._max = max(self._max - k, 0)
 
     @property
     def max_n(self) -> int:
@@ -125,9 +132,7 @@ class _DQueue:
         return self.capacity - self.max_n
 
     def drain(self) -> None:
-        self.n = jax.device_put(
-            jnp.zeros((self._eng.p,), jnp.int32), self._eng.sh(1)
-        )
+        self.n = jnp.zeros((self._eng.p,), jnp.int32, device=self._eng.sh(1))
         self._max = 0
         self._dirty = False
 
@@ -141,7 +146,7 @@ class _DScanRT:
     def __init__(self, eng: "DistributedEngine", desc: OpDesc, out_q: _DQueue):
         self.e, self.desc, self.out_q = eng, desc, out_q
         self.label = desc.label()
-        self.cursor = jax.device_put(jnp.zeros((eng.p,), jnp.int32), eng.sh(1))
+        self.cursor = jnp.zeros((eng.p,), jnp.int32, device=eng.sh(1))
         self.rounds_done = 0
         self.delta = desc.scan_epoch == "delta"
         if self.delta and eng.delta_adj is None:
@@ -202,6 +207,7 @@ class _DExtendRT:
         self.step = eng._build_extend_step(desc, self.is_verify)
         self._ref_step = None  # lazily-built unfused twin (kernel-fail path)
         self.query = ""
+        self.comm = jnp.zeros((2,), jnp.int32)  # [fetched, stolen], on device
         # The steal all_to_all is statically elided when a batch's worst-case
         # output can't be split P ways (mirrors the out_w >= p trace guard).
         self.steal_traced = (
@@ -242,7 +248,7 @@ class _DExtendRT:
                          self.label, self.query)
             if self._ref_step is None:
                 self._ref_step = e._build_extend_step(
-                    self.desc, self.is_verify, fused_override=False
+                    self.desc, self.is_verify, fallback=True
                 )
             step = self._ref_step
         if self.delta:
@@ -254,11 +260,16 @@ class _DExtendRT:
             rem, buf, n, comm = step(
                 e.adj, self.in_q.buf, self.in_q.n, self.out_q.buf, self.out_q.n
             )
-        self.in_q.set_n(rem)
+        self.in_q.popped(rem, e.cfg.batch_size)
         self.out_q.set(buf, n)
-        fetched, stolen = (int(x) for x in np.asarray(jnp.sum(comm, axis=0)))
+        self.comm = self.comm + jnp.sum(comm, axis=0)
         e.stats["rounds"] += 1
         e.stats["a2a_calls"] += 2 + (2 if self.steal_traced else 0)
+
+    def finish_stats(self) -> None:
+        """Fold the device-side traffic counters into the stats, once."""
+        e = self.e
+        fetched, stolen = (int(x) for x in np.asarray(self.comm))
         e.stats["pulled_vids"] += fetched
         e.stats["pulled_bytes"] += fetched * (e.d_pad + 2) * 4
         e.stats["steal_rows"] += stolen
@@ -277,15 +288,16 @@ class _DJoinRT:
         self.left_q, self.right_q, self.out_q = left_q, right_q, out_q
         self.label = desc.label()
         jcap = eng.cfg.join_buffer_capacity
-        shuffle_slack = eng.p * eng.cfg.batch_size
-        self.lbuf = _DQueue(eng, left_q.width, shuffle_slack, capacity=jcap)
-        self.rbuf = _DQueue(eng, right_q.width, shuffle_slack, capacity=jcap)
+        self.shuffle_slack = eng.p * eng.join_pop
+        self.lbuf = _DQueue(eng, left_q.width, self.shuffle_slack, capacity=jcap)
+        self.rbuf = _DQueue(eng, right_q.width, self.shuffle_slack, capacity=jcap)
         self.lshuf = eng._build_shuffle_step(desc.key_left[0])
         self.rshuf = eng._build_shuffle_step(desc.key_right[0])
         self.prep = eng._build_prepare_step(desc.key_left)
         self.probe = eng._build_probe_step(desc)
         self._ref_probe = None  # lazily-built unfused probe (kernel-fail path)
         self.query = ""
+        self.moved = {w: jnp.zeros((), jnp.int32) for w in ("l", "r")}
         self._sorted: Optional[Tuple[jax.Array, jax.Array]] = None
         # installed by the engine: () -> bool, True once every ancestor of the
         # left input (and the left queue itself) has drained
@@ -314,7 +326,7 @@ class _DJoinRT:
         return self.rbuf.max_n > 0
 
     def _runnable(self) -> Optional[str]:
-        shuffle_slack = self.e.p * self.e.cfg.batch_size
+        shuffle_slack = self.shuffle_slack
         if self.left_q.max_n > 0 and self.lbuf.free() >= shuffle_slack:
             return "lshuf"
         # Probing precedes shuffle-right so the probe drains rbuf and unblocks
@@ -338,29 +350,34 @@ class _DJoinRT:
 
     # -- execution -----------------------------------------------------------
 
-    def _shuffle(self, step, in_q: _DQueue, side: _DQueue) -> None:
+    def _shuffle(self, step, in_q: _DQueue, side: _DQueue, which: str) -> None:
         e = self.e
         rem, buf, n, moved = step(in_q.buf, in_q.n, side.buf, side.n)
-        in_q.set_n(rem)
+        in_q.popped(rem, e.join_pop)
         side.set(buf, n)
         assert self._sorted is None or side is self.rbuf, (
             "left side grew after the join barrier released"
         )
-        moved_rows = int(jnp.sum(moved))
+        self.moved[which] = self.moved[which] + jnp.sum(moved)
         e.stats["rounds"] += 1
         e.stats["a2a_calls"] += 1
-        e.stats["shuffle_rows"] += moved_rows
-        e.stats["shuffle_bytes"] += moved_rows * side.width * 4
+
+    def finish_stats(self) -> None:
+        e = self.e
+        for which, side in (("l", self.lbuf), ("r", self.rbuf)):
+            rows = int(self.moved[which])
+            e.stats["shuffle_rows"] += rows
+            e.stats["shuffle_bytes"] += rows * side.width * 4
 
     def run_one(self) -> None:
         e = self.e
         e._inject(("join-overflow", "shard-loss"), self.label, self.query)
         a = self._runnable()
         if a == "lshuf":
-            self._shuffle(self.lshuf, self.left_q, self.lbuf)
+            self._shuffle(self.lshuf, self.left_q, self.lbuf, "l")
             return
         if a == "rshuf":
-            self._shuffle(self.rshuf, self.right_q, self.rbuf)
+            self._shuffle(self.rshuf, self.right_q, self.rbuf, "r")
             return
         if self._sorted is None:
             # Barrier released: external merge sort of the buffered branch.
@@ -380,15 +397,18 @@ class _DJoinRT:
                     self.desc, use_kernel_override=False
                 )
             probe = self._ref_probe
-        out_buf, out_n, rem, overflow = probe(
+        out_buf, out_n, rem, stuck = probe(
             self._sorted[0], self._sorted[1], self.rbuf.buf, self.rbuf.n,
             self.out_q.buf, self.out_q.n,
         )
-        if bool(jnp.any(overflow)):
+        # A probe emits the matches of as many popped rows as fit in
+        # join_out_capacity and leaves the rest in rbuf; it is stuck only
+        # when one row alone has more matches than that.
+        if bool(jnp.any(stuck)):
             raise QueuePressure(
                 "join-overflow",
-                "distributed PUSH-JOIN probe exceeded join_out_capacity="
-                f"{e.cfg.join_out_capacity} (results would be lost)",
+                "a distributed PUSH-JOIN probe row has more matches than "
+                f"join_out_capacity={e.cfg.join_out_capacity}",
                 op=self.label, query=self.query,
             )
         self.rbuf.set_n(rem)
@@ -445,7 +465,8 @@ class DistributedEngine:
 
     def _sharded_edge_lists(self, graph: Graph):
         """Per-shard directed edge lists padded to the max shard size — the
-        scan source layout, shared by the full graph and the delta graph."""
+        scan source layout, shared by the full graph and the delta graph.
+        Built on the host and placed straight into their shards."""
         offsets = np.asarray(graph.offsets)
         deg_np = np.diff(offsets)
         src_all = np.repeat(np.arange(graph.num_vertices, dtype=np.int32), deg_np)
@@ -464,22 +485,37 @@ class DistributedEngine:
             dst[p, :n] = dst_all[sel]
             totals[p] = n
         return (
-            jax.device_put(jnp.asarray(src), self.sh(2)),
-            jax.device_put(jnp.asarray(dst), self.sh(2)),
-            jax.device_put(jnp.asarray(totals), self.sh(1)),
+            jax.device_put(src, self.sh(2)),
+            jax.device_put(dst, self.sh(2)),
+            jax.device_put(totals, self.sh(1)),
             max_e,
         )
 
     def _load_graph(self, graph: Graph) -> None:
-        """(Re)partition and bind every graph-derived device array."""
-        self.pg = partition_graph(graph, self.p)
+        """(Re)partition and bind every graph-derived device array. The
+        partition is built on the host and each shard's slice goes straight
+        to its own device."""
+        # The fetched table's keys run to 2·(P+1)·V (_fetch).
+        assert 2 * (self.p + 1) * graph.num_vertices < 2 ** 31, (
+            f"{graph.num_vertices} vertices on {self.p} shards overflow the "
+            "int32 fetched-table keys"
+        )
+        pg = partition_graph(graph, self.p)
         self.graph = graph
         self.v = graph.num_vertices
-        self.d_pad = self.pg.d_pad
-        self.adj = jax.device_put(self.pg.adj, self.sh(3))
+        self.d_pad = pg.d_pad
+        self.adj = jax.device_put(pg.adj, self.sh(3))
         self.src, self.dst, self.scan_totals, self.scan_len = (
             self._sharded_edge_lists(graph)
         )
+
+    @property
+    def join_pop(self) -> int:
+        """Rows a PUSH-JOIN shuffle or probe step takes from each shard: the
+        row budget of one extend step's output (batch × d_pad), spread over
+        the shards, so a shuffle's exchange is as large as an extend's."""
+        b = self.cfg.batch_size
+        return max(b, b * self.d_pad // self.p)
 
     # -- streaming updates (DESIGN.md §Delta-plans) ----------------------------
 
@@ -524,8 +560,15 @@ class DistributedEngine:
 
     def _fetch(self, adj, rows, valid_rows, ext):
         """Fetch stage: dedup needed vids, owner-routed exchange, return a
-        sorted lookup table (vids, adjacency rows) plus the number of requests
-        this shard routed to *other* shards (pull-traffic accounting)."""
+        lookup table (sorted keys, adjacency rows) plus the number of
+        requests this shard routed to *other* shards (pull-traffic
+        accounting).
+
+        The request lists come out of one sort by ``(owner, vid)``, so the
+        returned rows are already in key order: the table is searched by
+        ``_table_index`` without sorting it again (a sort of ``P·B·E`` keys
+        costs the TPU compiler seconds per shape, and reordering the rows
+        would copy the whole fetched table)."""
         p, axis = self.p, self.axis
         vids = rows[:, list(ext)].reshape(-1)
         ok = (
@@ -557,15 +600,21 @@ class DistributedEngine:
         served = jnp.take(adj, lid.reshape(-1), axis=0).reshape(p, r_cap, -1)
         served = jnp.where((got != INVALID)[:, :, None], served, INVALID)
         back = jax.lax.all_to_all(served, axis, split_axis=0, concat_axis=0, tiled=True)
-        back_vids = reqs.reshape(-1)
-        order = jnp.argsort(back_vids)
-        return (
-            jnp.take(back_vids, order),
-            jnp.take(back.reshape(-1, adj.shape[-1]), order, axis=0),
-            remote,
-        )
+        # Row o of ``reqs`` holds owner o's vids ascending, INVALID-padded:
+        # key 2·(o·V + vid) orders the flattened table, and padding gets
+        # 2·(o·V + V) − 1, which sorts after row o and before row o + 1.
+        owner_base = (jnp.arange(p, dtype=jnp.int32) * self.v)[:, None]
+        keys = jnp.where(reqs != INVALID, 2 * (owner_base + reqs),
+                         2 * (owner_base + self.v) - 1)
+        return keys.reshape(-1), back.reshape(-1, adj.shape[-1]), remote
 
-    def _lookup(self, table_vids, table_rows, adj, vids):
+    def _table_index(self, table_keys, vids):
+        """Row of each vid in the fetched table, and whether it is there."""
+        q = 2 * ((vids % self.p) * self.v + vids)
+        idx = jnp.clip(jnp.searchsorted(table_keys, q), 0, table_keys.shape[0] - 1)
+        return idx, (jnp.take(table_keys, idx) == q) & (vids >= 0) & (vids != INVALID)
+
+    def _lookup(self, table_keys, table_rows, adj, vids):
         p = self.p
         me = jax.lax.axis_index(self.axis)
         ok = (vids != INVALID) & (vids >= 0)
@@ -573,13 +622,12 @@ class DistributedEngine:
         lrows = jnp.take(
             adj, jnp.clip(jnp.where(ok, vids // p, 0), 0, adj.shape[0] - 1), axis=0
         )
-        idx = jnp.clip(jnp.searchsorted(table_vids, vids), 0, table_vids.shape[0] - 1)
-        hit = jnp.take(table_vids, idx) == vids
+        idx, hit = self._table_index(table_keys, vids)
         rrows = jnp.take(table_rows, idx, axis=0)
         rows = jnp.where(local[:, None], lrows, jnp.where(hit[:, None], rrows, INVALID))
         return jnp.where(ok[:, None], rows, INVALID)
 
-    def _fused_addressing(self, table_vids, adj, rows, ext):
+    def _fused_addressing(self, table_keys, adj, rows, ext):
         """The _lookup gather as fused-kernel slab addressing: tab0 = fetched
         remote table, tab1 = local adjacency. Returns (idx[2, B, E], sel, ok)
         with sel routing remote hits to the table and ok covering exactly the
@@ -590,8 +638,7 @@ class DistributedEngine:
         okv = (vids != INVALID) & (vids >= 0)
         local = okv & ((vids % p) == me)
         idx1 = jnp.clip(jnp.where(okv, vids // p, 0), 0, adj.shape[0] - 1)
-        idx0 = jnp.clip(jnp.searchsorted(table_vids, vids), 0, table_vids.shape[0] - 1)
-        hit = jnp.take(table_vids, idx0) == vids
+        idx0, hit = self._table_index(table_keys, vids)
         sel = (~local) & hit
         ok = okv & (local | hit)
         idx = jnp.stack([idx0.astype(jnp.int32), idx1.astype(jnp.int32)])
@@ -609,7 +656,7 @@ class DistributedEngine:
                 mesh=self.mesh,
                 in_specs=tuple(P(ax) for _ in range(n_in)),
                 out_specs=tuple(P(ax) for _ in range(n_out)) if n_out > 1 else P(ax),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -625,14 +672,17 @@ class DistributedEngine:
         return self._shardmap(f, 6, 2)
 
     def _build_extend_step(self, op: OpDesc, is_verify: bool,
-                           fused_override: Optional[bool] = None):
+                           fallback: bool = False):
+        """``fallback`` builds the kernel-free twin a failed kernel degrades
+        to: unfused, with the XLA binary-search membership."""
+        from repro.kernels.intersect import ops as ik
+
         b = self.cfg.batch_size
         ext, lt, gt = op.ext, op.lt_positions, op.gt_positions
         vpos = op.verify_pos
         rebalance = self.cfg.rebalance
-        fused, force_kernel = self.cfg.fused, self.cfg.force_kernel
-        if fused_override is not None:
-            fused = fused_override  # kernel-fail degradation builds a ref twin
+        fused = self.cfg.fused and not fallback
+        force_kernel = self.cfg.force_kernel
         p = self.p
         # Old-epoch ops veto delta membership against the *replicated* delta
         # adjacency (spec P() below); the fused kernels know nothing of
@@ -657,8 +707,6 @@ class DistributedEngine:
             stolen = jnp.zeros((), jnp.int32)
             k = rows.shape[1]
             if is_verify and fused:
-                from repro.kernels.intersect import ops as ik
-
                 idx, sel, okm = self._fused_addressing(tv, adj, rows, ext)
                 mask = valid & ik.fused_verify(
                     tr, adj, idx, sel, okm, rows, vpos=vpos,
@@ -680,8 +728,6 @@ class DistributedEngine:
                 out_w = b
             else:
                 if fused:
-                    from repro.kernels.intersect import ops as ik
-
                     idx, sel, okm = self._fused_addressing(tv, adj, rows, ext)
                     cands, mask = ik.fused_extend(
                         tr, adj, idx, sel, okm, rows, lt=lt, gt=gt,
@@ -691,13 +737,16 @@ class DistributedEngine:
                 else:
                     cands = self._lookup(tv, tr, adj, rows[:, ext[0]])
                     mask = (cands != INVALID) & valid[:, None]
-                    if old_mask[0]:
-                        mask = mask & ~ops_mod.row_membership(
-                            delta_rows(rows[:, ext[0]]), cands
-                        )
-                    for d, is_old in zip(ext[1:], old_mask[1:]):
-                        other = self._lookup(tv, tr, adj, rows[:, d])
-                        mask = mask & ops_mod.row_membership(other, cands)
+                    others = [self._lookup(tv, tr, adj, rows[:, d]) for d in ext[1:]]
+                    if fallback:
+                        for other in others:
+                            mask = mask & ops_mod.row_membership(other, cands)
+                    elif len(ext) > 1:
+                        # Native membership kernel on the TPU, where the
+                        # binary search is gather-bound; the jnp twin elsewhere.
+                        mask = mask & ik.multiway_membership(
+                            cands, jnp.stack(others, axis=1))
+                    for d, is_old in zip(ext, old_mask):
                         if is_old:
                             mask = mask & ~ops_mod.row_membership(
                                 delta_rows(rows[:, d]), cands
@@ -738,7 +787,7 @@ class DistributedEngine:
                     mesh=self.mesh,
                     in_specs=(P(),) + tuple(P(ax) for _ in range(5)),
                     out_specs=tuple(P(ax) for _ in range(4)),
-                    check_rep=False,
+                    check_vma=False,
                 )
             )
 
@@ -754,7 +803,7 @@ class DistributedEngine:
         """Pop a batch from an input queue, hash-route each row to shard
         ``row[key_col] % P`` with one all_to_all, append arrivals to the join
         side buffer. Also returns the number of rows that crossed shards."""
-        b = self.cfg.batch_size
+        b = self.join_pop
         p = self.p
 
         def f(in_buf, in_n, side_buf, side_n):
@@ -779,7 +828,7 @@ class DistributedEngine:
 
     def _build_probe_step(self, op: OpDesc,
                           use_kernel_override: Optional[bool] = None):
-        b = self.cfg.batch_size
+        b = self.join_pop
         out_cap = self.cfg.join_out_capacity
         key_right, right_extra = op.key_right, op.right_extra
         cross_neq, cross_lt = op.cross_neq, op.cross_lt
@@ -790,13 +839,14 @@ class DistributedEngine:
 
         def f(skeys, sbuf, r_buf, r_n, out_buf, out_n):
             rrows, take, rem = ops_mod.queue_pop(r_buf[0], r_n[0], b)
-            out, m, overflow = ops_mod.join_probe(
+            out, m, left = ops_mod.join_probe(
                 skeys[0], sbuf[0], rrows, take,
                 key_right, right_extra, cross_neq, cross_lt, out_cap,
                 use_kernel=use_kernel, force_kernel=force_kernel,
             )
             buf, n2 = ops_mod.queue_append(out_buf[0], out_n[0], out, m)
-            return buf[None], n2[None], rem[None], overflow[None]
+            stuck = (take > 0) & (left == take)
+            return buf[None], n2[None], (rem + left)[None], stuck[None]
 
         return self._shardmap(f, 6, 4)
 
@@ -1060,6 +1110,9 @@ class DistributedEngine:
                         )
                         self.cfg = dataclasses.replace(self.cfg, batch_size=nb)
                     continue
+                for rt in runtimes:
+                    if hasattr(rt, "finish_stats"):
+                        rt.finish_stats()
                 self.stats["sched_steps"] = st.steps
                 self.stats["sched_backtracks"] = st.backtracks
                 return runtimes, st

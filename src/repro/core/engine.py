@@ -79,7 +79,6 @@ class EngineConfig:
     cache_policy: str = "lrbu"             # "lrbu" | "lru" | "direct"
     materialize: bool = False              # keep final matches (tests only)
     materialize_cap: int = 1 << 20
-    use_intersect_kernel: bool = False     # Pallas membership inside extend_batch
     fused: bool = False                    # fused hot path: LRBU value-cache
     #   probe → slab gather → intersect in one kernel pass (extend/verify) and
     #   the compare-count bounds kernel inside PUSH-JOIN probes
@@ -179,6 +178,68 @@ _POLICIES = {
 }
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("ext", "num_machines", "num_vertices", "policy"),
+    donate_argnums=(0,),
+)
+def _fetch_accounting(cache, deg, rows, n, ext: Tuple[int, ...],
+                      num_machines: int, num_vertices: int, policy):
+    """One batch of the simulated fetch stage as a single program: route the
+    remote vertices, run them through the per-machine caches (``policy``
+    None = no cache), and return the updated caches with ``[pulled_bytes,
+    hits, misses]`` — read back by the host in one transfer."""
+    b = rows.shape[0]
+    row_valid = jnp.arange(b) < n
+    shard = jnp.where(rows[:, 0] >= 0, rows[:, 0] % num_machines, 0)
+    vids = rows[:, list(ext)]                       # [B, E]
+    machs = jnp.broadcast_to(shard[:, None], vids.shape)
+    remote = (vids % num_machines) != machs
+    valid = row_valid[:, None] & (vids != INVALID) & (vids >= 0) & remote
+    vids_f = vids.reshape(-1)
+    reqs, _ = route_requests(
+        vids_f, machs.reshape(-1), valid.reshape(-1), num_machines,
+        num_vertices, r_cap=vids_f.shape[0],
+    )
+    req_valid = reqs != INVALID
+    if policy is not None:
+        cache, hit = jax.vmap(_POLICIES[policy])(cache, reqs)
+        hit = hit & req_valid
+    else:
+        hit = jnp.zeros_like(req_valid)
+    miss = req_valid & ~hit
+    degs = jnp.where(miss, jnp.take(deg, jnp.clip(reqs, 0, num_vertices - 1)), 0)
+    pulled = jnp.sum((degs + 2) * 4 * miss)
+    return cache, jnp.stack([pulled, jnp.sum(hit), jnp.sum(miss)]).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("ext", "cached"), donate_argnums=(0,))
+def _fused_addressing(vcache, adj, deg, rows, ext: Tuple[int, ...], cached: bool):
+    """Slab addressing of the fused kernels for one batch, as one program:
+    insert the batch's deduped vertices into the LRBU value cache (when
+    ``cached``), then probe it. Returns ``(vcache, idx[2, B, E], sel, ok)``:
+    hits read cache slabs (tab0), misses the adjacency table (tab1)."""
+    v = adj.shape[0]
+    vids = rows[:, list(ext)]                       # [B, E]
+    ok = (vids >= 0) & (vids < v)
+    idx1 = jnp.clip(vids, 0, v - 1)
+    if cached:
+        flat = jnp.where(ok, vids, INVALID).reshape(-1)
+        uniq = ops_mod.dedup_pad(flat)
+        safe = jnp.clip(uniq, 0, v - 1)
+        slabs = jnp.take(adj, safe, axis=0)
+        degs = jnp.where(uniq != INVALID, jnp.take(deg, safe), 0)
+        vcache, _ = lrbu.fetch_update_values(vcache, uniq, slabs, degs)
+        idx0, hit = lrbu.probe_indices(vcache, flat)
+        idx0 = idx0.reshape(vids.shape)
+        sel = hit.reshape(vids.shape)
+    else:
+        idx0 = jnp.zeros_like(idx1)
+        sel = jnp.zeros(vids.shape, bool)
+    idx = jnp.stack([idx0, idx1]).astype(jnp.int32)
+    return vcache, idx, sel.astype(jnp.int32), ok.astype(jnp.int32)
+
+
 # ---------------------------------------------------------------------------
 # Device queues
 # ---------------------------------------------------------------------------
@@ -195,14 +256,14 @@ class DeviceQueue:
 
     def append(self, rows: jax.Array, m) -> int:
         m_host = int(m)
-        if self.n + m_host > self.capacity:
+        if self.n + max(m_host, rows.shape[0]) > self.capacity:
             # Recoverable pressure, not a crash: the drive()/service recovery
             # ladder restores the last checkpoint at a halved batch (Lemma 5.2
             # slack is a soft bound under degradation).
             raise QueuePressure(
                 "queue-overflow",
-                f"{self.n}+{m_host} > {self.capacity} rows "
-                "(scheduler slack invariant violated)",
+                f"{self.n}+max({m_host}, {rows.shape[0]}) > {self.capacity} "
+                "rows (scheduler slack invariant violated)",
                 op=self.label, query=self.query,
             )
         self.buf, _ = ops_mod.queue_append(self.buf, jnp.int32(self.n), rows, m)
@@ -211,7 +272,7 @@ class DeviceQueue:
 
     def pop(self, batch: int) -> Tuple[jax.Array, jax.Array]:
         rows, take, _ = ops_mod.queue_pop(self.buf, jnp.int32(self.n), batch)
-        self.n -= int(take)
+        self.n -= min(self.n, batch)  # the host already knows ``take``
         return rows, take
 
     def free(self) -> int:
@@ -343,7 +404,6 @@ class _ExtendRT(_BaseRT):
             out, m = ops_mod.extend_batch(
                 e.adj, rows, n, self.desc.ext, self.desc.lt_positions,
                 self.desc.gt_positions, self.batch * e.d_pad,
-                use_kernel=e.cfg.use_intersect_kernel,
             )
         cnt = self.out_q.append(out, m)
         e.stats.compute_time += time.perf_counter() - t0
@@ -944,8 +1004,8 @@ class HugeEngine:
     def _load_graph(self, graph: Graph) -> None:
         """(Re)bind every graph-derived array — also the update path's spine."""
         self.graph = graph
-        self.adj = graph.padded.adj
-        self.deg = graph.padded.deg
+        self.adj = jnp.asarray(graph.padded.adj)
+        self.deg = jnp.asarray(graph.padded.deg)
         self.d_pad = graph.padded.d_pad
         assert graph.num_vertices * self.cfg.num_machines < 2**31, (
             "machine-id × vertex-id key must fit int32"
@@ -962,7 +1022,6 @@ class HugeEngine:
             self._cache = _make_stacked_cache(
                 self.cfg.num_machines, self.cfg.cache_capacity, ways
             )
-            self._cache_update = jax.vmap(_POLICIES[self.cfg.cache_policy])
         # Device-level LRBU *value* cache serving adjacency slabs to the fused
         # kernels (the per-machine caches above are stats-only simulation).
         self._vcache = None
@@ -985,7 +1044,7 @@ class HugeEngine:
         self._load_graph(applied.graph)
         self._reset_caches()
         delta = applied.delta
-        self.delta_adj = delta.padded.adj
+        self.delta_adj = jnp.asarray(delta.padded.adj)
         self.delta_src_pad, self.delta_dst_pad = _edge_scan_arrays(
             delta, self.cfg.batch_size
         )
@@ -1034,36 +1093,15 @@ class HugeEngine:
     def fetch_stage(self, rows: jax.Array, n: jax.Array, ext: Tuple[int, ...]) -> None:
         t0 = time.perf_counter()
         cfg = self.cfg
-        b, k = rows.shape
-        row_valid = jnp.arange(b) < n
-        shard = jnp.where(rows[:, 0] >= 0, rows[:, 0] % cfg.num_machines, 0)
-        vids = rows[:, list(ext)]                       # [B, E]
-        machs = jnp.broadcast_to(shard[:, None], vids.shape)
-        remote = (vids % cfg.num_machines) != machs
-        valid = (
-            row_valid[:, None] & (vids != INVALID) & (vids >= 0) & remote
+        self._cache, acct = _fetch_accounting(
+            self._cache, self.deg, rows, n, tuple(ext), cfg.num_machines,
+            self.graph.num_vertices,
+            cfg.cache_policy if self._cache is not None else None,
         )
-        vids_f = vids.reshape(-1)
-        machs_f = machs.reshape(-1)
-        valid_f = valid.reshape(-1)
-        reqs, cnt = route_requests(
-            vids_f, machs_f, valid_f, cfg.num_machines, self.graph.num_vertices,
-            r_cap=vids_f.shape[0],
-        )
-        req_valid = reqs != INVALID
-        if self._cache is not None:
-            self._cache, hit = self._cache_update(self._cache, reqs)
-            hit = hit & req_valid
-        else:
-            hit = jnp.zeros_like(req_valid)
-        miss = req_valid & ~hit
-        degs = jnp.where(
-            miss, jnp.take(self.deg, jnp.clip(reqs, 0, self.graph.num_vertices - 1)), 0
-        )
-        pulled = jnp.sum((degs + 2) * 4 * miss)
-        self.stats.pulled_bytes += int(pulled)
-        self.stats.cache_hits += int(jnp.sum(hit))
-        self.stats.cache_misses += int(jnp.sum(miss))
+        pulled, hits, misses = (int(x) for x in np.asarray(acct))
+        self.stats.pulled_bytes += pulled
+        self.stats.cache_hits += hits
+        self.stats.cache_misses += misses
         self.stats.comm_time += time.perf_counter() - t0
 
     # -- fused hot path: value-cache probe prologue ----------------------------
@@ -1073,27 +1111,15 @@ class HugeEngine:
         kernels for one batch: insert the batch's deduped vertices into the
         LRBU value cache (seal/release), then probe it — hits read cache slabs
         (tab0), misses fall back to the adjacency table (tab1)."""
-        v = self.graph.num_vertices
-        vids = rows[:, list(ext)]                       # [B, E]
-        ok = (vids >= 0) & (vids < v)
-        idx1 = jnp.clip(vids, 0, v - 1)
+        self._vcache, idx, sel, ok = _fused_addressing(
+            self._vcache, self.adj, self.deg, rows, tuple(ext),
+            self._vcache is not None,
+        )
         if self._vcache is not None:
-            flat = jnp.where(ok, vids, INVALID).reshape(-1)
-            uniq = ops_mod.dedup_pad(flat)
-            safe = jnp.clip(uniq, 0, v - 1)
-            slabs = jnp.take(self.adj, safe, axis=0)
-            degs = jnp.where(uniq != INVALID, jnp.take(self.deg, safe), 0)
-            self._vcache, _ = lrbu.fetch_update_values(self._vcache, uniq, slabs, degs)
-            idx0, hit = lrbu.probe_indices(self._vcache, flat)
             tab0 = self._vcache.values.reshape(-1, self.d_pad)
-            idx0 = idx0.reshape(vids.shape)
-            sel = hit.reshape(vids.shape)
         else:
             tab0 = self.adj[:1]
-            idx0 = jnp.zeros_like(idx1)
-            sel = jnp.zeros(vids.shape, bool)
-        idx = jnp.stack([idx0, idx1])
-        return tab0, self.adj, idx, sel.astype(jnp.int32), ok.astype(jnp.int32)
+        return tab0, self.adj, idx, sel, ok
 
     # -- push accounting for wco-push extends (BiGJoin-style plans) -----------
 

@@ -33,15 +33,71 @@ def row_membership(sorted_rows: jax.Array, queries: jax.Array) -> jax.Array:
     return (found == queries) & (queries != INVALID)
 
 
+_SCAN_BLOCK = 128
+
+
+def prefix_count(mask: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a 0/1 vector, as int32.
+
+    Blocked: each 128-entry block is summed against a triangular matrix on
+    the MXU and the block totals recurse. Exact (every partial sum stays
+    below 2^24 and the products run at full f32 precision), and it compiles
+    in well under a second at any length, where the TPU compiler takes
+    seconds per shape on a long ``jnp.cumsum``."""
+    x = mask.astype(jnp.float32)
+    n = x.shape[0]
+    assert n < 1 << 24, f"prefix_count over {n} entries would lose exactness"
+    tri = (jnp.arange(_SCAN_BLOCK)[:, None] <= jnp.arange(_SCAN_BLOCK)[None, :])
+    if n <= _SCAN_BLOCK:
+        return jnp.dot(x, tri[:n, :n].astype(jnp.float32),
+                       precision=lax.Precision.HIGHEST).astype(jnp.int32)
+    blocks = jnp.pad(x, (0, (-n) % _SCAN_BLOCK)).reshape(-1, _SCAN_BLOCK)
+    within = jnp.dot(blocks, tri.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST).astype(jnp.int32)
+    totals = within[:, -1]
+    before = prefix_count(totals) - totals
+    return (within + before[:, None]).reshape(-1)[:n]
+
+
+def prefix_sum_sat(x: jax.Array, cap: int) -> jax.Array:
+    """Inclusive prefix sum of a non-negative int32 vector, saturated at
+    ``cap`` (exact wherever the true sum is below ``cap``; ``cap`` itself
+    elsewhere), with ``128·cap < 2^31``.
+
+    Blocked like ``prefix_count``, but in int32 shifted adds within each
+    128-entry block: ``lax.associative_scan`` over 10^6 entries costs the
+    TPU compiler minutes."""
+    assert _SCAN_BLOCK * cap < 1 << 31, cap
+    n = x.shape[0]
+    x = jnp.minimum(x, cap)
+    s = jnp.pad(x, (0, (-n) % _SCAN_BLOCK)).reshape(-1, _SCAN_BLOCK)
+    k = 1
+    while k < _SCAN_BLOCK:
+        s = s + jnp.pad(s[:, :-k], ((0, 0), (k, 0)))
+        k *= 2
+    if s.shape[0] > 1:
+        before = jnp.pad(prefix_sum_sat(s[:, -1], cap)[:-1], (1, 0))
+        s = s + before[:, None]
+    return jnp.minimum(s, cap).reshape(-1)[:n]
+
+
 def compact(rows: jax.Array, mask: jax.Array, out_cap: int) -> Tuple[jax.Array, jax.Array]:
-    """Pack masked rows to the front of a fresh [out_cap, K] buffer."""
-    k = rows.shape[-1]
-    pos = jnp.cumsum(mask) - 1
+    """Pack masked rows to the front of a fresh [out_cap, K] buffer.
+
+    The rows move by a gather: a 1-D scatter writes each kept row's source
+    index into its output slot, then whole rows are taken. (Scattering the
+    rows themselves is equivalent, but the TPU compiler spends seconds on
+    every 2-D row scatter, once per shape; ``prefix_count`` avoids the same
+    cost in ``jnp.cumsum``.)"""
+    n_in = rows.shape[0]
+    pos = prefix_count(mask) - 1
     n = jnp.sum(mask, dtype=jnp.int32)
     tgt = jnp.where(mask, pos, out_cap)  # out-of-range → dropped by scatter
-    out = jnp.full((out_cap, k), INVALID, dtype=jnp.int32)
-    out = out.at[tgt].set(rows, mode="drop")
-    return out, n
+    src = jnp.full((out_cap,), n_in, jnp.int32).at[tgt].set(
+        jnp.arange(n_in, dtype=jnp.int32), mode="drop"
+    )
+    out = jnp.take(rows, jnp.minimum(src, n_in - 1), axis=0)
+    return jnp.where((src < n_in)[:, None], out, INVALID), n
 
 
 @jax.jit
@@ -77,11 +133,14 @@ def lexsort_rows(cols: jax.Array) -> jax.Array:
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def queue_append(buf: jax.Array, n: jax.Array, rows: jax.Array, m: jax.Array):
+    """Write ``rows`` (valid prefix of ``m``) at the stack top ``n``.
+
+    One contiguous write of all ``R`` rows: rows past ``m`` land in free
+    space and are never read. Requires ``n + R <= CAP`` — the Lemma-5.2
+    slack the scheduler reserves before it runs a producer (a write that
+    did not fit would be shifted down over live rows)."""
     cap = buf.shape[0]
-    r = rows.shape[0]
-    idx = n + jnp.arange(r, dtype=jnp.int32)
-    tgt = jnp.where(jnp.arange(r) < m, idx, cap)
-    buf = buf.at[tgt].set(rows, mode="drop")
+    buf = lax.dynamic_update_slice(buf, rows, (n, jnp.int32(0)))
     return buf, jnp.minimum(n + m, cap)
 
 
@@ -99,28 +158,17 @@ def partition_rows_by_key(rows: jax.Array, valid: jax.Array, key: jax.Array,
     """Group rows by destination shard ``key % num_shards`` for an all_to_all.
 
     Returns ``send[P, B, K]`` (INVALID-padded): ``send[d]`` holds the rows
-    destined to shard ``d``, packed to the front. This is the send tensor of
-    the PUSH-JOIN hash shuffle (DESIGN.md §Shuffle-join) — the collective
-    itself lives in distributed.py; this part is pure and unit-testable.
+    destined to shard ``d``, packed to the front in input order. This is the
+    send tensor of the PUSH-JOIN hash shuffle (DESIGN.md §Shuffle-join) — the
+    collective itself lives in distributed.py; this part is pure and
+    unit-testable. One ``compact`` per destination: no sort, which the TPU
+    compiler takes seconds over at the shuffle's batch sizes.
     """
-    b, k = rows.shape
+    b = rows.shape[0]
     dest = jnp.where(valid, key % num_shards, num_shards)
-    order = jnp.argsort(dest, stable=True)
-    sdest = jnp.take(dest, order)
-    srows = jnp.take(rows, order, axis=0)
-    cnt = jax.ops.segment_sum(
-        (sdest < num_shards).astype(jnp.int32), sdest, num_segments=num_shards + 1
-    )[:num_shards]
-    offs = jnp.cumsum(cnt) - cnt
-    offs_ext = jnp.concatenate([offs, jnp.zeros((1,), jnp.int32)])
-    slot = jnp.arange(b, dtype=jnp.int32) - jnp.take(
-        offs_ext, jnp.minimum(sdest, num_shards)
+    return jnp.stack(
+        [compact(rows, dest == d, b)[0] for d in range(num_shards)]
     )
-    ok = sdest < num_shards
-    send = jnp.full((num_shards, b, k), INVALID, jnp.int32).at[
-        jnp.where(ok, sdest, num_shards), jnp.where(ok, slot, b)
-    ].set(srows, mode="drop")
-    return send
 
 
 # ---------------------------------------------------------------------------
@@ -166,8 +214,14 @@ def extend_batch(
     lt: Tuple[int, ...],
     gt: Tuple[int, ...],
     out_cap: int,
-    use_kernel: bool = False,
+    use_kernel: bool = True,
 ):
+    """Extend each row by the common neighbours of its ``ext`` columns.
+
+    Eq.-2 membership goes through ``kernels/intersect`` (the native kernel
+    on the TPU, where the XLA binary search is gather-bound; its jnp twin
+    elsewhere). ``use_kernel=False`` is the kernel-free path a failed
+    kernel degrades to."""
     b, k = rows.shape
     v = adj.shape[0]
     valid_row = jnp.arange(b) < n
@@ -441,6 +495,13 @@ def join_probe(
     use_kernel: bool = False,
     force_kernel: bool = False,
 ):
+    """Probe the first ``rn`` right rows against the sorted left side.
+
+    Emits the matches of the *last* right rows whose matches fit in
+    ``out_cap`` together — the top of the stack the rows were popped from —
+    and returns ``(out, n_out, left)``: ``left`` right rows, the first ones,
+    were not probed. A caller that can push them back resumes from there;
+    one that cannot treats ``left > 0`` as an overflow."""
     b, kr = rrows.shape
     rvalid = jnp.arange(b) < rn
     rkeys = jnp.where(rvalid[:, None], rrows[:, list(key_right)], INVALID - 1)
@@ -451,8 +512,13 @@ def join_probe(
     else:
         lo, hi = _lex_bounds(sorted_keys, rkeys)
     cnt = jnp.where(rvalid, hi - lo, 0)
-    off = jnp.cumsum(cnt) - cnt
+    # Matches of rows i.. (saturating: only "fits in out_cap" matters).
+    after = prefix_sum_sat(cnt[::-1], out_cap + 1)[::-1]
+    take = rvalid & (after <= out_cap)
+    cnt = jnp.where(take, cnt, 0)
+    off = prefix_sum_sat(cnt, out_cap + 1) - cnt
     total = jnp.sum(cnt)
+    left = jnp.sum(rvalid & ~take, dtype=jnp.int32)
 
     o = jnp.arange(out_cap, dtype=jnp.int32)
     g = jnp.searchsorted(off + cnt, o, side="right").astype(jnp.int32)
@@ -474,7 +540,7 @@ def join_probe(
         valid = valid & (out[:, a] < out[:, bcol])
     out = jnp.where(valid[:, None], out, INVALID)
     out2, nout = compact(out, valid, out_cap)
-    return out2, nout, total > out_cap
+    return out2, nout, left
 
 
 # ---------------------------------------------------------------------------

@@ -28,7 +28,7 @@ def shortest_path_length(graph: Graph, source: int, target: int, max_hops: int =
     v = graph.num_vertices
     dist = jnp.full((v,), jnp.iinfo(jnp.int32).max, jnp.int32).at[source].set(0)
     frontier = jnp.zeros((v,), bool).at[source].set(True)
-    adj = graph.padded.adj
+    adj = jnp.asarray(graph.padded.adj)
     for hop in range(1, max_hops + 1):
         # neighbours of the whole frontier in one gather (PULL-EXTEND fetch)
         rows = jnp.where(frontier[:, None], adj, INVALID)

@@ -22,8 +22,8 @@ from repro.graph.storage import Graph, INVALID
 class PartitionedGraph:
     """Padded adjacency stacked by shard. Owner(v) = v % P, local(v) = v // P."""
 
-    adj: jax.Array  # int32[P, V_per, D_pad]
-    deg: jax.Array  # int32[P, V_per]
+    adj: np.ndarray  # int32[P, V_per, D_pad] (host; engines shard it)
+    deg: np.ndarray  # int32[P, V_per]
     num_vertices: int
     num_shards: int
 
@@ -70,8 +70,8 @@ def partition_graph(graph: Graph, num_shards: int) -> PartitionedGraph:
     deg[owners, locals_] = full_deg
 
     return PartitionedGraph(
-        adj=jnp.asarray(adj),
-        deg=jnp.asarray(deg),
+        adj=adj,
+        deg=deg,
         num_vertices=v,
         num_shards=num_shards,
     )

@@ -14,6 +14,11 @@ sentinel ``INVALID`` (int32 max). Sorted rows + a monotone sentinel mean that
 
 ``D_pad`` is the max degree rounded up to a lane multiple (128) so Pallas
 kernels can tile rows directly.
+
+A :class:`Graph` holds host (numpy) arrays. Each engine places what it
+needs where it needs it: the one-chip engine on its device, the
+distributed engine straight into per-shard ``NamedSharding``s — so no
+array is staged through device 0 on the way.
 """
 from __future__ import annotations
 
@@ -40,8 +45,8 @@ def _round_up(x: int, m: int) -> int:
 class PaddedAdjacency:
     """Dense, padded adjacency: ``adj[v]`` = sorted neighbours of v, INVALID-padded."""
 
-    adj: jax.Array  # int32[V, D_pad]
-    deg: jax.Array  # int32[V]
+    adj: np.ndarray  # int32[V, D_pad]
+    deg: np.ndarray  # int32[V]
 
     def __post_init__(self):
         # The Pallas fused kernels tile adjacency rows directly, so D_pad must
@@ -84,10 +89,10 @@ class PaddedAdjacency:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class Graph:
-    """An undirected data graph in CSR + padded form (device resident)."""
+    """An undirected data graph in CSR + padded form (host resident)."""
 
-    offsets: jax.Array  # int32[V+1]
-    nbrs: jax.Array  # int32[2E] sorted within each row
+    offsets: np.ndarray  # int32[V+1]
+    nbrs: np.ndarray  # int32[2E] sorted within each row
     padded: PaddedAdjacency
 
     @property
@@ -104,7 +109,7 @@ class Graph:
 
     @property
     def max_degree(self) -> int:
-        return int(np.asarray(jnp.max(self.padded.deg)))
+        return int(np.max(self.padded.deg))
 
     @property
     def avg_degree(self) -> float:
@@ -190,9 +195,7 @@ def build_graph(edges: np.ndarray, num_vertices: int, d_pad: int | None = None) 
     adj[row_idx, col_idx] = nbrs
 
     return Graph(
-        offsets=jnp.asarray(offsets),
-        nbrs=jnp.asarray(nbrs),
-        padded=PaddedAdjacency(adj=jnp.asarray(adj), deg=jnp.asarray(deg)),
+        offsets=offsets, nbrs=nbrs, padded=PaddedAdjacency(adj=adj, deg=deg)
     )
 
 
@@ -333,9 +336,9 @@ def apply_updates(graph: Graph, batch: GraphUpdateBatch) -> AppliedUpdates:
         adj[t, row.shape[0] :] = INVALID
 
     new_graph = Graph(
-        offsets=jnp.asarray(new_offsets),
-        nbrs=jnp.asarray(new_nbrs.astype(np.int32)),
-        padded=PaddedAdjacency(adj=jnp.asarray(adj), deg=jnp.asarray(new_deg)),
+        offsets=new_offsets,
+        nbrs=new_nbrs.astype(np.int32),
+        padded=PaddedAdjacency(adj=adj, deg=new_deg.astype(np.int32)),
     )
     return AppliedUpdates(
         graph=new_graph, delta=delta,

@@ -29,7 +29,7 @@ import jax
 from repro.configs import ARCH_NAMES, SHAPES, get_config, shape_skip_reason
 from repro.launch import hlo_counter
 from repro.launch.mesh import (
-    DCI_BW, HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh,
+    DCI_BW, HBM_BW, ICI_BW, PEAK_FLOPS_BF16, auto_mesh, make_production_mesh,
 )
 from repro.launch.specs import input_specs
 from repro.models import sharding as shd
@@ -46,9 +46,9 @@ def _make_mesh(multi: bool):
         return make_production_mesh(multi_pod=multi)
     if multi:
         model = max(2, n // 4)
-        return jax.make_mesh((2, n // (2 * model), model), ("pod", "data", "model"))
+        return auto_mesh((2, n // (2 * model), model), ("pod", "data", "model"))
     model = max(2, n // 2)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str) -> dict:

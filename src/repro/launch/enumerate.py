@@ -15,6 +15,7 @@ from repro.core.cost import GraphStats
 from repro.core.dataflow import translate
 from repro.core.query import PAPER_QUERIES
 from repro.graph import powerlaw_graph
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -31,6 +32,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--verify", action="store_true", help="check against networkx")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     graph = powerlaw_graph(args.vertices, args.avg_degree, seed=args.seed)
     query = PAPER_QUERIES[args.query]

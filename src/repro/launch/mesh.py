@@ -13,19 +13,31 @@ models/partitioning.py).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding constraints,
+    shard_maps and NamedShardings in this repository are written for Auto
+    semantics, while ``jax.make_mesh`` defaults to ``Explicit`` axes (which
+    ``with_sharding_constraint`` refuses to name)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1):
     """Small mesh over whatever devices exist (tests, examples)."""
     if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 # Hardware constants (TPU v5e) used by the roofline analysis.

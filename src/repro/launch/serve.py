@@ -124,6 +124,9 @@ def graph_main(argv=None):
 
 
 def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     if argv and argv[0] == "graph":
         return graph_main(argv[1:])

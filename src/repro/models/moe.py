@@ -28,7 +28,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.models.layers import dense_init
 from repro.models.sharding import active_mesh, axis_size, batch_axes, pspec, shard
@@ -218,7 +218,7 @@ def _moe_push(params, x, experts_per_token, capacity_factor, mesh):
             P(ep_spec, None, tp_spec), P(ep_spec, tp_spec, None),
         ),
         out_specs=P(bspec[0]),
-        check_rep=False,
+        check_vma=False,
     )(xt, params["router"], params["w_gate"], params["w_up"], params["w_down"])
     return out.reshape(b, s, d)
 
@@ -262,7 +262,7 @@ def _moe_pull(params, x, experts_per_token, capacity_factor, mesh, replicated_to
             P(ep_spec, None, tp_spec), P(ep_spec, tp_spec, None),
         ),
         out_specs=P(bspec),
-        check_rep=False,
+        check_vma=False,
     )(xt, params["router"], params["w_gate"], params["w_up"], params["w_down"])
     return out.reshape(b, s, d)
 

@@ -20,7 +20,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _quant(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -57,7 +57,7 @@ def compressed_psum_mean(flat: jax.Array, axis: str, mesh) -> jax.Array:
         full = _dequant(qg, sg).reshape(-1)[:size + pad]
         return full[:size] if pad == 0 else full[:size]
 
-    return shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)(flat)
+    return shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)(flat)
 
 
 def compress_gradients(grads, mesh, axis: str = "pod", error_state=None):

@@ -12,6 +12,7 @@ from typing import Tuple
 import jax
 from jax.sharding import Mesh
 
+from repro.launch.mesh import auto_mesh
 from repro.models import transformer as T
 from repro.models.partitioning import param_shardings
 from repro.train import checkpoint as ckpt
@@ -24,7 +25,7 @@ def make_mesh_from_available(model_axis: int = 1) -> Mesh:
     devs = jax.devices()
     n = len(devs)
     assert n % model_axis == 0, (n, model_axis)
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return auto_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def reshard_checkpoint(

@@ -337,6 +337,7 @@ def test_distributed_chaos_matrix():
     must recover (restart or degraded batch) to the oracle count."""
     out = _run_py(f"""
         import jax
+        from repro.launch.mesh import auto_mesh
         from repro.core import query as Q
         from repro.core.distributed import DistributedEngine, DistConfig
         from repro.core.faults import FaultPlan
@@ -344,7 +345,7 @@ def test_distributed_chaos_matrix():
         from repro.graph.oracle import count_instances
 
         SEED = {SEED}
-        mesh = jax.make_mesh((4,), ("shards",))
+        mesh = auto_mesh((4,), ("shards",))
         g = powerlaw_graph(220, 5.0, seed=4)
         q = Q.PAPER_QUERIES["q1"]
         oracle = count_instances(g, list(q.edges))

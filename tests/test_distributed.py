@@ -23,11 +23,12 @@ def run_py(code: str, timeout=540, devices=8) -> str:
 def test_distributed_engine_matches_oracle():
     out = run_py("""
         import jax
+        from repro.launch.mesh import auto_mesh
         from repro.graph import erdos_renyi
         from repro.graph.oracle import count_instances
         from repro.core import query as Q
         from repro.core.distributed import DistributedEngine, DistConfig
-        mesh = jax.make_mesh((8,), ("shards",))
+        mesh = auto_mesh((8,), ("shards",))
         g = erdos_renyi(250, 6.0, seed=11)
         eng = DistributedEngine(g, mesh, DistConfig(batch_size=128, queue_capacity=1<<14))
         for qname in ("q1", "q2", "q3"):
@@ -43,11 +44,12 @@ def test_distributed_engine_matches_oracle():
 def test_distributed_work_stealing_toggle():
     out = run_py("""
         import jax
+        from repro.launch.mesh import auto_mesh
         from repro.graph import powerlaw_graph
         from repro.graph.oracle import count_instances
         from repro.core import query as Q
         from repro.core.distributed import DistributedEngine, DistConfig
-        mesh = jax.make_mesh((8,), ("shards",))
+        mesh = auto_mesh((8,), ("shards",))
         g = powerlaw_graph(300, 6.0, seed=12)
         q = Q.PAPER_QUERIES["q1"]
         oracle = count_instances(g, list(q.edges))
@@ -67,11 +69,12 @@ def test_distributed_push_join_hybrid_plans():
     power-law and clique-heavy graphs."""
     out = run_py("""
         import jax
+        from repro.launch.mesh import auto_mesh
         from repro.graph import powerlaw_graph, ring_of_cliques
         from repro.graph.oracle import count_instances
         from repro.core import query as Q
         from repro.core.distributed import DistributedEngine, DistConfig
-        mesh = jax.make_mesh((4,), ("shards",))
+        mesh = auto_mesh((4,), ("shards",))
         pl = powerlaw_graph(240, 5.0, seed=3)
         cl = ring_of_cliques(24, 5)
         cases = [
@@ -97,6 +100,34 @@ def test_distributed_push_join_hybrid_plans():
     assert out.count("ok") == 4
 
 
+def test_distributed_probe_resumes_past_out_capacity():
+    """A probe emits only as many matches as join_out_capacity holds and
+    leaves the rest of its rows for the next probe: a small output capacity
+    takes more probe steps, no recovery, and the same oracle count."""
+    out = run_py("""
+        from repro.launch.mesh import auto_mesh
+        from repro.graph import powerlaw_graph
+        from repro.graph.oracle import count_instances
+        from repro.core import query as Q
+        from repro.core.distributed import DistributedEngine, DistConfig
+        mesh = auto_mesh((4,), ("shards",))
+        g = powerlaw_graph(300, 6.0, seed=5)
+        q = Q.PAPER_QUERIES["q2"]
+        oracle = count_instances(g, list(q.edges))
+        probes = []
+        for out_cap in (1 << 14, 64):
+            eng = DistributedEngine(g, mesh, DistConfig(
+                batch_size=128, queue_capacity=1 << 14, join_out_capacity=out_cap))
+            count, stats = eng.run(q, space="seed")
+            assert count == oracle, (out_cap, count, oracle)
+            assert stats["retries"] == 0 and stats["joins"] == 1, stats
+            probes.append(stats["probe_batches"])
+        assert probes[1] > probes[0], probes
+        print("ok", oracle, probes)
+    """, devices=4)
+    assert "ok" in out
+
+
 def test_distributed_fused_hot_path_matches_unfused():
     """The fused extend/verify and probe kernels inside the shard_map engine
     produce counts identical to the unfused collectives path and the oracle —
@@ -104,11 +135,12 @@ def test_distributed_fused_hot_path_matches_unfused():
     executes real Pallas kernel semantics inside shard_map."""
     out = run_py("""
         import jax
+        from repro.launch.mesh import auto_mesh
         from repro.graph import powerlaw_graph, ring_of_cliques
         from repro.graph.oracle import count_instances
         from repro.core import query as Q
         from repro.core.distributed import DistributedEngine, DistConfig
-        mesh = jax.make_mesh((4,), ("shards",))
+        mesh = auto_mesh((4,), ("shards",))
         pl = powerlaw_graph(240, 5.0, seed=3)
         for qname, space in (("q1", "huge"), ("q2", "seed"), ("q7", "huge")):
             q = Q.PAPER_QUERIES[qname]
@@ -138,11 +170,12 @@ def test_distributed_mixed_tenants_run_concurrent():
     equal both isolated runs and the networkx oracle."""
     out = run_py("""
         import jax
+        from repro.launch.mesh import auto_mesh
         from repro.graph import erdos_renyi
         from repro.graph.oracle import count_instances
         from repro.core import query as Q
         from repro.core.distributed import DistributedEngine, DistConfig
-        mesh = jax.make_mesh((4,), ("shards",))
+        mesh = auto_mesh((4,), ("shards",))
         g = erdos_renyi(200, 5.0, seed=13)
         eng = DistributedEngine(g, mesh, DistConfig(batch_size=128, queue_capacity=1<<14))
         queries = [Q.PAPER_QUERIES[n] for n in ("q1", "q2", "q3")]
@@ -165,10 +198,11 @@ def test_moe_push_pull_equivalence_multidevice():
     same logical join — identical outputs, different collectives."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.models import sharding as shd
         from repro.models.moe import moe_init, moe_block
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         key = jax.random.key(0)
         params = moe_init(key, 32, 64, 8, jnp.float32)
         x = jax.random.normal(jax.random.key(1), (8, 16, 32), jnp.float32)
@@ -193,8 +227,9 @@ def test_moe_push_pull_equivalence_multidevice():
 def test_compressed_psum_accuracy():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from repro.train.compress import compressed_psum_mean
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = auto_mesh((8,), ("pod",))
         x = jax.random.normal(jax.random.key(0), (10000,), jnp.float32)
         with mesh:
             got = compressed_psum_mean(x, "pod", mesh)
@@ -210,13 +245,14 @@ def test_train_step_runs_sharded():
     """A real sharded train step on a (4, 2) mesh: loss finite, params move."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from repro.configs import smoke_config
         from repro.models import sharding as shd
         from repro.models.partitioning import param_shardings
         from repro.train.train_step import TrainConfig, make_train_step, init_all
         from repro.train.optimizer import AdamWConfig
         cfg = smoke_config("qwen3-moe-30b-a3b")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         tc = TrainConfig(adamw=AdamWConfig(learning_rate=1e-3))
         with shd.activate(mesh), mesh:
             params, opt = init_all(cfg, tc, jax.random.key(0))
@@ -272,9 +308,10 @@ def test_elastic_reshard_8_to_4(tmp_path):
 def test_hlo_counter_counts_collectives_in_loops():
     out = run_py("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import auto_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.hlo_counter import analyze
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = auto_mesh((8,), ("data",))
         def f(x, w):
             def body(c, _):
                 y = jax.lax.with_sharding_constraint(c @ w, NamedSharding(mesh, P(None, None)))

@@ -55,6 +55,34 @@ def test_join_prepare_probe_vs_bruteforce():
     assert got == want
 
 
+def test_join_probe_leaves_rows_past_out_cap():
+    """Rows whose matches do not fit stay unprobed, counted in ``left``: the
+    probed rows are the last ones (the top of the stack they came from)."""
+    lbuf = jnp.asarray([[k, k] for k in (1, 1, 1, 2, 2, 3)] + [[0, 0]] * 2, jnp.int32)
+    skeys, sbuf = ops.join_prepare(lbuf, jnp.int32(6), (0,))
+    rrows = jnp.asarray([[3, 30], [1, 10], [2, 20], [3, 31]], jnp.int32)
+    # matches per right row: 1, 3, 2, 1 -> the last three fit in 4 slots
+    out, n, left = ops.join_probe(skeys, sbuf, rrows, jnp.int32(4),
+                                  (0,), (1,), (), (), 4)
+    assert int(left) == 2 and int(n) == 3
+    got = sorted(tuple(map(int, r)) for r in np.asarray(out[: int(n)]))
+    assert got == [(2, 2, 20), (2, 2, 20), (3, 3, 31)]
+    _, n, left = ops.join_probe(skeys, sbuf, rrows, jnp.int32(2),
+                                (0,), (1,), (), (), 2)
+    assert int(left) == 2 and int(n) == 0   # one row alone overflows
+
+
+def test_prefix_sum_sat_matches_numpy():
+    rng = np.random.default_rng(1)
+    for n in (1, 127, 128, 129, 5000, 40000):
+        x = rng.integers(0, 40, n).astype(np.int32)
+        x[rng.integers(0, n, 2)] = 1 << 20
+        for cap in (7, 1000, 1 << 19):
+            want = np.minimum(np.cumsum(x.astype(np.int64)), cap)
+            got = np.asarray(ops.prefix_sum_sat(jnp.asarray(x), cap))
+            np.testing.assert_array_equal(got, want)
+
+
 def test_join_probe_cross_filters():
     lbuf = jnp.asarray([[1, 5, 2], [3, 5, 4]], jnp.int32)
     rbuf = jnp.asarray([[5, 2], [5, 9]], jnp.int32)
