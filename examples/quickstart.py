@@ -19,7 +19,7 @@ for query in (PAPER_QUERIES["q1"], clique(4)):
     s = res.stats
     print(
         f"{query.name:10s} count={res.count:>10,}  "
-        f"T={s.wall_time:.2f}s (compute {s.compute_time:.2f}s / comm {s.comm_time:.2f}s)  "
+        f"T={s.wall_time:.2f}s  "
         f"pulled={s.pulled_bytes / 1e6:.1f}MB pushed={s.pushed_bytes / 1e6:.1f}MB "
         f"cache-hits={s.hit_rate:.0%}  peak-mem={s.peak_queue_bytes / 1e6:.1f}MB"
     )

@@ -52,6 +52,7 @@ from repro.core.optimizer import optimal_plan
 from repro.core.plan import ExecutionPlan
 from repro.core.query import QueryGraph
 from repro.core.scheduler import AdaptiveScheduler, ScheduleStats
+from repro.core.tracing import host_read, span
 from repro.graph.storage import (
     AppliedUpdates,
     Graph,
@@ -102,8 +103,7 @@ class EngineStats:
     cache_misses: int = 0
     batches: int = 0
     rows_emitted: int = 0
-    compute_time: float = 0.0   # T_R analogue: intersect/join/scan
-    comm_time: float = 0.0      # T_C analogue: fetch stage (routing + cache)
+    host_syncs: int = 0         # device-to-host reads (tracing.host_read)
     peak_queue_rows: int = 0
     peak_queue_bytes: int = 0
     join_overflows: int = 0
@@ -254,8 +254,8 @@ class DeviceQueue:
         self.label = label   # producing op's label (fault attribution)
         self.query = query   # owning dataflow's query name
 
-    def append(self, rows: jax.Array, m) -> int:
-        m_host = int(m)
+    def append(self, rows: jax.Array, m, stats: EngineStats) -> int:
+        m_host = int(host_read(stats, "queue_count", m))
         if self.n + max(m_host, rows.shape[0]) > self.capacity:
             # Recoverable pressure, not a crash: the drive()/service recovery
             # ladder restores the last checkpoint at a halved batch (Lemma 5.2
@@ -331,7 +331,6 @@ class _ScanRT(_BaseRT):
     def run_one(self) -> None:
         e = self.e
         e._inject(("queue-overflow", "shard-loss"), self.label, self.query)
-        t0 = time.perf_counter()
         src = e.delta_src_pad if self.delta else e.src_pad
         dst = e.delta_dst_pad if self.delta else e.dst_pad
         rows, n = ops_mod.scan_batch(
@@ -339,8 +338,7 @@ class _ScanRT(_BaseRT):
             self.batch, self.desc.lt_positions, self.desc.gt_positions,
         )
         self.cursor += self.batch
-        m = self.out_q.append(rows, n)
-        e.stats.compute_time += time.perf_counter() - t0
+        m = self.out_q.append(rows, n, e.stats)
         e.stats.batches += 1
         e.stats.rows_emitted += m
 
@@ -365,7 +363,6 @@ class _ExtendRT(_BaseRT):
             e.fetch_stage(rows, n, self.desc.ext)
         elif self.comm == "push":
             e.push_wco_stage(rows, n, len(self.desc.ext), rows.shape[1])
-        t0 = time.perf_counter()
         if "old" in self.desc.ext_epochs:
             # Old-epoch positions veto delta membership; the fused kernels
             # know nothing of epochs, so delta extends take the plain path
@@ -405,8 +402,7 @@ class _ExtendRT(_BaseRT):
                 e.adj, rows, n, self.desc.ext, self.desc.lt_positions,
                 self.desc.gt_positions, self.batch * e.d_pad,
             )
-        cnt = self.out_q.append(out, m)
-        e.stats.compute_time += time.perf_counter() - t0
+        cnt = self.out_q.append(out, m, e.stats)
         e.stats.batches += 1
         e.stats.rows_emitted += cnt
 
@@ -429,7 +425,6 @@ class _VerifyRT(_BaseRT):
         rows, n = self.in_q.pop(self.batch)
         if self.comm == "pull":
             e.fetch_stage(rows, n, self.desc.ext)
-        t0 = time.perf_counter()
         if "old" in self.desc.ext_epochs:
             out, m = ops_mod.delta_verify_batch(
                 e.adj, e.delta_adj, rows, n, self.desc.ext,
@@ -459,8 +454,7 @@ class _VerifyRT(_BaseRT):
             out, m = ops_mod.verify_batch(
                 e.adj, rows, n, self.desc.ext, self.desc.verify_pos, self.batch
             )
-        cnt = self.out_q.append(out, m)
-        e.stats.compute_time += time.perf_counter() - t0
+        cnt = self.out_q.append(out, m, e.stats)
         e.stats.batches += 1
         e.stats.rows_emitted += cnt
 
@@ -499,14 +493,10 @@ class _JoinRT(_BaseRT):
         if self._prepared is None:
             # The left branch is complete (barrier, §5.4): merge-sort it by key
             # once — the paper's buffered external sort.
-            t0 = time.perf_counter()
             self._prepared = ops_mod.join_prepare(
                 self.left_q.buf, jnp.int32(self.left_q.n), self.desc.key_left
             )
-            e.stats.compute_time += time.perf_counter() - t0
         rrows, rn = self.right_q.pop(max(64, self.batch))
-        e.stats.pushed_bytes += int(int(rn) * self.right_q.width * 4 * frac)
-        t0 = time.perf_counter()
         use_kernel = e.cfg.fused
         if use_kernel and e.cfg.faults is not None and e.cfg.faults.should_fire(
             "kernel-fail", self.label
@@ -523,7 +513,9 @@ class _JoinRT(_BaseRT):
             self.desc.cross_neq, self.desc.cross_lt, e.cfg.join_out_capacity,
             use_kernel=use_kernel, force_kernel=e.cfg.force_kernel,
         )
-        if bool(overflow):
+        rn, overflow = host_read(e.stats, "join_count", (rn, overflow))
+        e.stats.pushed_bytes += int(int(rn) * self.right_q.width * 4 * frac)
+        if overflow:
             e.stats.join_overflows += 1
             raise QueuePressure(
                 "join-overflow",
@@ -532,8 +524,7 @@ class _JoinRT(_BaseRT):
                 "(results would be lost)",
                 op=self.label, query=self.query,
             )
-        cnt = self.out_q.append(out, m)
-        e.stats.compute_time += time.perf_counter() - t0
+        cnt = self.out_q.append(out, m, e.stats)
         e.stats.batches += 1
         e.stats.rows_emitted += cnt
 
@@ -552,14 +543,15 @@ class _SinkRT(_BaseRT):
     def run_one(self) -> None:
         e = self.e
         rows, n = self.in_q.pop(self.drain)
-        n_host = int(n)
+        n_host = int(host_read(e.stats, "sink_count", n))
         e.stats.count += n_host
         if e.cfg.materialize and sum(r.shape[0] for r in self.rows_out) < e.cfg.materialize_cap:
-            host = np.asarray(rows[:n_host] if n_host <= rows.shape[0] else rows)
+            host = host_read(e.stats, "sink_count",
+                             rows[:n_host] if n_host <= rows.shape[0] else rows)
             self.rows_out.append(host[:n_host])
         # Track per-machine result distribution for the load-balance experiment.
         if e.track_balance and n_host:
-            host = np.asarray(rows)[:n_host]
+            host = host_read(e.stats, "sink_count", rows)[:n_host]
             owners = host[:, 0] % e.cfg.num_machines
             np.add.at(e.balance_rows, owners, 1)
         e.stats.batches += 1
@@ -620,7 +612,7 @@ class _ScopedRT:
     stats mutation — runtimes, fetch_stage, push accounting — goes through
     ``engine.stats``), keeping the underlying runtimes untouched."""
 
-    __slots__ = ("rt", "e", "stats", "label", "session")
+    __slots__ = ("rt", "e", "stats", "label", "session", "span")
 
     def __init__(self, rt: _BaseRT, engine: "HugeEngine", stats: EngineStats,
                  session: "EngineSession" = None):
@@ -629,6 +621,7 @@ class _ScopedRT:
         self.stats = stats
         self.label = rt.label
         self.session = session
+        self.span = f"op.{rt.desc.kind}"
 
     def has_input(self) -> bool:
         return self.rt.has_input()
@@ -643,7 +636,8 @@ class _ScopedRT:
         prev = self.e.stats
         self.e.stats = self.stats
         try:
-            self.rt.run_one()
+            with span(self.span):
+                self.rt.run_one()
         except EnumerationFault as f:
             # Attribute the fault to the owning session so a multi-tenant
             # scheduler pass can fail/recover exactly one query.
@@ -1091,18 +1085,18 @@ class HugeEngine:
     # -- fetch stage (pull accounting) ---------------------------------------
 
     def fetch_stage(self, rows: jax.Array, n: jax.Array, ext: Tuple[int, ...]) -> None:
-        t0 = time.perf_counter()
-        cfg = self.cfg
-        self._cache, acct = _fetch_accounting(
-            self._cache, self.deg, rows, n, tuple(ext), cfg.num_machines,
-            self.graph.num_vertices,
-            cfg.cache_policy if self._cache is not None else None,
-        )
-        pulled, hits, misses = (int(x) for x in np.asarray(acct))
-        self.stats.pulled_bytes += pulled
-        self.stats.cache_hits += hits
-        self.stats.cache_misses += misses
-        self.stats.comm_time += time.perf_counter() - t0
+        with span("engine.fetch_stage"):
+            cfg = self.cfg
+            self._cache, acct = _fetch_accounting(
+                self._cache, self.deg, rows, n, tuple(ext), cfg.num_machines,
+                self.graph.num_vertices,
+                cfg.cache_policy if self._cache is not None else None,
+            )
+            pulled, hits, misses = (
+                int(x) for x in host_read(self.stats, "fetch_accounting", acct))
+            self.stats.pulled_bytes += pulled
+            self.stats.cache_hits += hits
+            self.stats.cache_misses += misses
 
     # -- fused hot path: value-cache probe prologue ----------------------------
 
@@ -1125,7 +1119,8 @@ class HugeEngine:
 
     def push_wco_stage(self, rows: jax.Array, n: jax.Array, n_ext: int, k: int) -> None:
         frac = (self.cfg.num_machines - 1) / max(1, self.cfg.num_machines)
-        self.stats.pushed_bytes += int(int(n) * k * 4 * n_ext * frac)
+        n = int(host_read(self.stats, "push_count", n))
+        self.stats.pushed_bytes += int(n * k * 4 * n_ext * frac)
 
     # -- memory probe ----------------------------------------------------------
 
@@ -1182,21 +1177,23 @@ class HugeEngine:
         caches, and the process-global jit cache, while each owns its
         slot-slice of device queues and its own stats (the multi-tenant
         substrate; see serve/graph_service.py)."""
-        flow = self.to_flow(query_or_plan, space, stats)
-        # Mandatory pre-flight (DESIGN.md §Static-analysis): a malformed flow
-        # must fail here with structured diagnostics, not mid-run on device.
-        # Imported lazily — analysis.flowcheck imports core.dataflow, and the
-        # repro.core package itself imports this module.
-        from repro.analysis.flowcheck import verify_flow
+        with span("engine.prepare"):
+            flow = self.to_flow(query_or_plan, space, stats)
+            # Mandatory pre-flight (DESIGN.md §Static-analysis): a malformed
+            # flow must fail here with structured diagnostics, not mid-run on
+            # device. Imported lazily — analysis.flowcheck imports
+            # core.dataflow, and the repro.core package itself imports this
+            # module.
+            from repro.analysis.flowcheck import verify_flow
 
-        verify_flow(flow, cfg=self.cfg, d_pad=self.d_pad,
-                    queue_capacity=queue_capacity,
-                    join_buffer_capacity=join_buffer_capacity)
-        return EngineSession(
-            self, flow, stats=session_stats,
-            queue_capacity=queue_capacity,
-            join_buffer_capacity=join_buffer_capacity,
-        )
+            verify_flow(flow, cfg=self.cfg, d_pad=self.d_pad,
+                        queue_capacity=queue_capacity,
+                        join_buffer_capacity=join_buffer_capacity)
+            return EngineSession(
+                self, flow, stats=session_stats,
+                queue_capacity=queue_capacity,
+                join_buffer_capacity=join_buffer_capacity,
+            )
 
     def drive(self, session: EngineSession) -> EngineSession:
         """Run a session to completion under the graceful-degradation ladder

@@ -159,6 +159,7 @@ def multiway_membership_kernel(cands: jax.Array, others: jax.Array, *, interpret
         out_specs=pl.BlockSpec((TILE_B, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.int32),
         interpret=interpret,
+        name="multiway_membership_kernel",
     )(cands, jnp.transpose(others, (1, 0, 2)))
     return out != 0
 
@@ -243,8 +244,8 @@ def _fused_extend_kernel_body(
     )
 
 
-def _slab_call(body, out_specs, out_shape, tab0, tab1, idx, sel, ok, rows,
-               interpret: bool):
+def _slab_call(name, body, out_specs, out_shape, tab0, tab1, idx, sel, ok,
+               rows, interpret: bool):
     """Shared pallas_call plumbing of the fused extend/verify kernels: batch
     padded to TILE_B, addressing scalars prefetched into SMEM, tables left in
     HBM for the body's aligned-block DMAs."""
@@ -279,6 +280,7 @@ def _slab_call(body, out_specs, out_shape, tab0, tab1, idx, sel, ok, rows,
         grid_spec=grid_spec,
         out_shape=out_shape(bp, d),
         interpret=interpret,
+        name=name,
     )(*scalars, _pad_rows(rows, bp, INVALID),
       _pad_rows(tab0, 8, INVALID), _pad_rows(tab1, 8, INVALID))
 
@@ -305,6 +307,7 @@ def fused_extend_kernel(
     b = rows.shape[0]
     tile_out = pl.BlockSpec((TILE_B, tab0.shape[1]), lambda i, *_: (i, 0))
     cands, mask = _slab_call(
+        "fused_extend_kernel",
         functools.partial(_fused_extend_kernel_body, lt=lt, gt=gt),
         [tile_out, tile_out],
         lambda bp, d: [jax.ShapeDtypeStruct((bp, d), jnp.int32)] * 2,
@@ -350,6 +353,7 @@ def fused_verify_kernel(
     row; lane 0 is read back."""
     b = rows.shape[0]
     out = _slab_call(
+        "fused_verify_kernel",
         functools.partial(_fused_verify_kernel_body, vpos=vpos),
         pl.BlockSpec((TILE_B, CHUNK), lambda i, *_: (i, 0)),
         lambda bp, d: jax.ShapeDtypeStruct((bp, CHUNK), jnp.int32),
@@ -423,6 +427,7 @@ def lex_bounds_kernel(
         out_specs=pl.BlockSpec((TILE_B, 2), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, 2), jnp.int32),
         interpret=interpret,
+        name="lex_bounds_kernel",
     )(sorted_keys, queries)
     lo = out[:b, 0]
     return lo, lo + out[:b, 1]

@@ -53,7 +53,7 @@ def main(argv=None):
     print(
         f"\n[enumerate] {args.query} on |V|={args.vertices} (space={args.space}): "
         f"count={res.count}\n"
-        f"  T={s.wall_time:.2f}s (T_R={s.compute_time:.2f}s, T_C={s.comm_time:.2f}s)\n"
+        f"  T={s.wall_time:.2f}s\n"
         f"  C: pulled={s.pulled_bytes / 1e6:.2f}MB pushed={s.pushed_bytes / 1e6:.2f}MB "
         f"cache-hit-rate={s.hit_rate:.2%}\n"
         f"  M: peak queue {s.peak_queue_bytes / 1e6:.2f}MB ({s.peak_queue_rows} rows)"

@@ -180,11 +180,11 @@ def test_organic_queue_overflow_is_recoverable_pressure():
     # slack gating prevents this state; the queue itself stays defensive.
     import jax.numpy as jnp
 
-    from repro.core.engine import DeviceQueue
+    from repro.core.engine import DeviceQueue, EngineStats
 
     q = DeviceQueue(capacity=100, width=2, label="EXT(v2)", query="q1")
     with pytest.raises(QueuePressure) as ei:
-        q.append(jnp.zeros((128, 2), jnp.int32), jnp.int32(128))
+        q.append(jnp.zeros((128, 2), jnp.int32), jnp.int32(128), EngineStats())
     f = ei.value
     assert f.kind == "queue-overflow" and f.recoverable
     assert f.op == "EXT(v2)" and f.query == "q1"

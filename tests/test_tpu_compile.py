@@ -14,6 +14,7 @@ process may hold the TPU library, and every test worker imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import pytest
 
@@ -121,3 +122,33 @@ def test_unfused_extend_step_compiles(spec, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16 << 30, used
+
+
+def _small_kernel_calls(spec):
+    """Each Pallas kernel of the hot path with small shapes, by its name."""
+    from repro.kernels.intersect import intersect as k
+
+    b, d = 64, 256
+    slabs = (spec((1 << 10, d)), spec((1 << 10, d)), spec((2, b, 2)),
+             spec((b, 2)), spec((b, 2)), spec((b, K)))
+    return {
+        "multiway_membership_kernel": lambda: k.multiway_membership_kernel.lower(
+            spec((b, d)), spec((b, 2, d))),
+        "fused_extend_kernel": lambda: k.fused_extend_kernel.lower(*slabs, lt=(0,)),
+        "fused_verify_kernel": lambda: k.fused_verify_kernel.lower(*slabs, vpos=2),
+        "lex_bounds_kernel": lambda: k.lex_bounds_kernel.lower(
+            spec((1 << 10, 2)), spec((b, 2))),
+    }
+
+
+@pytest.mark.parametrize("name", ["multiway_membership_kernel",
+                                  "fused_extend_kernel", "fused_verify_kernel",
+                                  "lex_bounds_kernel"])
+def test_kernel_keeps_its_name(spec, name):
+    """The kernel's own name is pinned, and the chip's custom call, whose
+    name a device trace shows, carries it."""
+    lowered = _small_kernel_calls(spec)[name]()
+    assert f'kernel_name = "{name}"' in lowered.as_text()
+    hlo = lowered.compile().as_text()
+    assert re.search(rf"%{name}(\.\d+)? = .* custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', hlo), name
