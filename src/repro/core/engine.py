@@ -24,6 +24,7 @@ import copy
 import dataclasses
 import functools
 import logging
+import math
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -104,6 +105,7 @@ class EngineStats:
     batches: int = 0
     rows_emitted: int = 0
     host_syncs: int = 0         # device-to-host reads (tracing.host_read)
+    extend_resumes: int = 0     # extend calls past a step's first (_ExtendRT)
     peak_queue_rows: int = 0
     peak_queue_bytes: int = 0
     join_overflows: int = 0
@@ -254,9 +256,11 @@ class DeviceQueue:
         self.label = label   # producing op's label (fault attribution)
         self.query = query   # owning dataflow's query name
 
-    def append(self, rows: jax.Array, m, stats: EngineStats) -> int:
+    def append(self, rows: jax.Array, m, stats: EngineStats, skip: int = 0) -> int:
+        """Append the step's rows ``skip …`` that ``rows`` holds, of the
+        ``m`` the step emits; returns ``m`` as read."""
         m_host = int(host_read(stats, "queue_count", m))
-        if self.n + max(m_host, rows.shape[0]) > self.capacity:
+        if self.n + max(m_host - skip, rows.shape[0]) > self.capacity:
             # Recoverable pressure, not a crash: the drive()/service recovery
             # ladder restores the last checkpoint at a halved batch (Lemma 5.2
             # slack is a soft bound under degradation).
@@ -267,7 +271,7 @@ class DeviceQueue:
                 op=self.label, query=self.query,
             )
         self.buf, _ = ops_mod.queue_append(self.buf, jnp.int32(self.n), rows, m)
-        self.n += m_host
+        self.n += min(m_host - skip, rows.shape[0])
         return m_host
 
     def pop(self, batch: int) -> Tuple[jax.Array, jax.Array]:
@@ -355,6 +359,25 @@ class _ExtendRT(_BaseRT):
     def required_slack(self) -> int:
         return self.batch * self.e.d_pad
 
+    def _extend(self, rows, n, use_kernel: bool = True) -> int:
+        """The unfused extend, ``W = batch × extend_lanes`` rows per call:
+        a step that emits more resumes at ``j0 = W, 2W, …`` until all of it
+        is appended (one more count read each). Returns the step's count."""
+        e = self.e
+        w = self.batch * e.extend_lanes
+        j0 = 0
+        while True:
+            out, m = ops_mod.extend_batch(
+                e.adj, rows, n, self.desc.ext, self.desc.lt_positions,
+                self.desc.gt_positions, w, use_kernel=use_kernel,
+                j0=jnp.int32(j0),
+            )
+            total = self.out_q.append(out, m, e.stats, skip=j0)
+            j0 += w
+            if j0 >= total:
+                return total
+            e.stats.extend_resumes += 1
+
     def run_one(self) -> None:
         e = self.e
         e._inject(("queue-overflow", "shard-loss"), self.label, self.query)
@@ -373,6 +396,7 @@ class _ExtendRT(_BaseRT):
                 self.desc.lt_positions, self.desc.gt_positions,
                 self.batch * e.d_pad,
             )
+            cnt = self.out_q.append(out, m, e.stats)
         elif e.cfg.fused:
             try:
                 if e.cfg.faults is not None and e.cfg.faults.should_fire(
@@ -386,23 +410,16 @@ class _ExtendRT(_BaseRT):
                     self.desc.lt_positions, self.desc.gt_positions,
                     self.batch * e.d_pad, force_kernel=e.cfg.force_kernel,
                 )
+                cnt = self.out_q.append(out, m, e.stats)
             except KernelFault as kf:
                 # One-shot graceful degradation: the ref twin is exact, so a
                 # failed kernel batch is recomputed unfused instead of failing
                 # the query (stat: kernel_fallbacks).
                 e.stats.kernel_fallbacks += 1
                 _log.warning("fused extend fell back to ref twin: %s", kf)
-                out, m = ops_mod.extend_batch(
-                    e.adj, rows, n, self.desc.ext, self.desc.lt_positions,
-                    self.desc.gt_positions, self.batch * e.d_pad,
-                    use_kernel=False,
-                )
+                cnt = self._extend(rows, n, use_kernel=False)
         else:
-            out, m = ops_mod.extend_batch(
-                e.adj, rows, n, self.desc.ext, self.desc.lt_positions,
-                self.desc.gt_positions, self.batch * e.d_pad,
-            )
-        cnt = self.out_q.append(out, m, e.stats)
+            cnt = self._extend(rows, n)
         e.stats.batches += 1
         e.stats.rows_emitted += cnt
 
@@ -1001,6 +1018,12 @@ class HugeEngine:
         self.adj = jnp.asarray(graph.padded.adj)
         self.deg = jnp.asarray(graph.padded.deg)
         self.d_pad = graph.padded.d_pad
+        # Unfused extend rows per batch row and call: three times the mean
+        # degree, a power of two that divides d_pad (so a resumed step ends
+        # inside its B·d_pad queue slack).
+        self.extend_lanes = min(
+            1 << math.ceil(math.log2(max(3 * graph.avg_degree, 1))),
+            self.d_pad & -self.d_pad)
         assert graph.num_vertices * self.cfg.num_machines < 2**31, (
             "machine-id × vertex-id key must fit int32"
         )

@@ -36,24 +36,30 @@ def row_membership(sorted_rows: jax.Array, queries: jax.Array) -> jax.Array:
 _SCAN_BLOCK = 128
 
 
-def prefix_count(mask: jax.Array) -> jax.Array:
-    """Inclusive prefix sum of a 0/1 vector, as int32.
+def row_prefix(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sums along the last axis of a non-negative integer
+    array, as int32, by one product with a triangular matrix on the MXU.
+    Exact while each row's total stays below 2^24 (the products run at full
+    f32 precision); meant for short axes, up to a few hundred entries."""
+    w = x.shape[-1]
+    tri = (jnp.arange(w)[:, None] <= jnp.arange(w)[None, :]).astype(jnp.float32)
+    return jnp.dot(x.astype(jnp.float32), tri,
+                   precision=lax.Precision.HIGHEST).astype(jnp.int32)
 
-    Blocked: each 128-entry block is summed against a triangular matrix on
-    the MXU and the block totals recurse. Exact (every partial sum stays
-    below 2^24 and the products run at full f32 precision), and it compiles
-    in well under a second at any length, where the TPU compiler takes
-    seconds per shape on a long ``jnp.cumsum``."""
-    x = mask.astype(jnp.float32)
+
+def prefix_count(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a non-negative int vector whose total stays
+    below 2^24 (a 0/1 mask of any length under that), as int32.
+
+    Blocked: each 128-entry block goes through ``row_prefix`` and the block
+    totals recurse. It compiles in well under a second at any length, where
+    the TPU compiler takes seconds per shape on a long ``jnp.cumsum``."""
     n = x.shape[0]
     assert n < 1 << 24, f"prefix_count over {n} entries would lose exactness"
-    tri = (jnp.arange(_SCAN_BLOCK)[:, None] <= jnp.arange(_SCAN_BLOCK)[None, :])
     if n <= _SCAN_BLOCK:
-        return jnp.dot(x, tri[:n, :n].astype(jnp.float32),
-                       precision=lax.Precision.HIGHEST).astype(jnp.int32)
-    blocks = jnp.pad(x, (0, (-n) % _SCAN_BLOCK)).reshape(-1, _SCAN_BLOCK)
-    within = jnp.dot(blocks, tri.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST).astype(jnp.int32)
+        return row_prefix(x)
+    within = row_prefix(
+        jnp.pad(x, (0, (-n) % _SCAN_BLOCK)).reshape(-1, _SCAN_BLOCK))
     totals = within[:, -1]
     before = prefix_count(totals) - totals
     return (within + before[:, None]).reshape(-1)[:n]
@@ -98,6 +104,53 @@ def compact(rows: jax.Array, mask: jax.Array, out_cap: int) -> Tuple[jax.Array, 
     )
     out = jnp.take(rows, jnp.minimum(src, n_in - 1), axis=0)
     return jnp.where((src < n_in)[:, None], out, INVALID), n
+
+
+def compact_extend(rows: jax.Array, cands: jax.Array, mask: jax.Array,
+                   j0: jax.Array, out_cap: int) -> Tuple[jax.Array, jax.Array]:
+    """Rows ``j0 … j0+out_cap`` of the extension ``(rows[r], cands[r, c])``
+    over every kept ``mask[r, c]``, in row-major order, as a fresh
+    ``[out_cap, K+1]`` buffer (INVALID past the end), with the true count.
+
+    No index array spans ``[B, D]``: that work is dense (the masked block
+    sums), and every scatter or gather runs over ``B`` or ``out_cap``
+    indices. Slot ``j`` finds its row by the row offsets' prefix (each row
+    marks its first slot, a prefix count over the slots turns the marks
+    into row numbers), its rank ``t`` within the row, then its 128-lane
+    block of the row by the blocks' prefix and its lane by a prefix inside
+    that one gathered block."""
+    b, k = rows.shape
+    lanes = _SCAN_BLOCK
+    pad = (-cands.shape[1]) % lanes
+    nb = (cands.shape[1] + pad) // lanes
+    assert b * nb * lanes < 1 << 24, "offsets would lose exactness"
+    kept = jnp.pad(jnp.where(mask, cands, INVALID), ((0, 0), (0, pad)),
+                   constant_values=INVALID).reshape(b * nb, lanes)
+    per_block = jnp.sum(kept != INVALID, axis=1, dtype=jnp.int32).reshape(b, nb)
+    block_start = row_prefix(per_block) - per_block               # [B, nb]
+    count = jnp.sum(per_block, axis=1, dtype=jnp.int32)
+    start = prefix_count(count) - count                            # [B]
+    n = start[-1] + count[-1]
+
+    marks = jnp.zeros((out_cap,), jnp.int32).at[
+        jnp.where(start >= j0, start - j0, out_cap)].add(1, mode="drop")
+    r = jnp.sum(start < j0, dtype=jnp.int32) + prefix_count(marks) - 1
+    r = jnp.clip(r, 0, b - 1)
+    head = jnp.take(jnp.concatenate([rows, start[:, None], block_start], 1),
+                    r, axis=0)                                     # [W, K+1+nb]
+    j = j0 + jnp.arange(out_cap, dtype=jnp.int32)
+    t = j - head[:, k]
+    firsts = head[:, k + 1:]
+    blk = jnp.sum(firsts[:, 1:] <= t[:, None], axis=1, dtype=jnp.int32)
+    t = t - jnp.sum(jnp.where(jnp.arange(nb) == blk[:, None], firsts, 0),
+                    axis=1, dtype=jnp.int32)
+    got = jnp.take(kept, r * nb + blk, axis=0)                    # [W, 128]
+    lane = jnp.sum(row_prefix(got != INVALID) <= t[:, None], axis=1,
+                   dtype=jnp.int32)
+    cand = jnp.sum(jnp.where(jnp.arange(lanes) == lane[:, None], got, 0),
+                   axis=1, dtype=jnp.int32)
+    out = jnp.concatenate([head[:, :k], cand[:, None]], axis=1)
+    return jnp.where((j < n)[:, None], out, INVALID), n
 
 
 @jax.jit
@@ -215,8 +268,13 @@ def extend_batch(
     gt: Tuple[int, ...],
     out_cap: int,
     use_kernel: bool = True,
+    j0: jax.Array | int = 0,
 ):
     """Extend each row by the common neighbours of its ``ext`` columns.
+
+    Returns rows ``j0 … j0+out_cap`` of the step's output (``compact_extend``)
+    and its true count, which may exceed ``out_cap``: a caller resumes at
+    ``j0 + out_cap`` with the same compiled shape.
 
     Eq.-2 membership goes through ``kernels/intersect`` (the native kernel
     on the TPU, where the XLA binary search is gather-bound; its jnp twin
@@ -252,16 +310,7 @@ def extend_batch(
         mask = mask & (cands < jnp.where(valid_row, rows[:, p], -1)[:, None])
     for p in gt:
         mask = mask & (cands > jnp.where(valid_row, rows[:, p], INVALID)[:, None])
-
-    d = cands.shape[1]
-    expanded = jnp.concatenate(
-        [
-            jnp.broadcast_to(rows[:, None, :], (b, d, k)),
-            cands[:, :, None],
-        ],
-        axis=2,
-    ).reshape(b * d, k + 1)
-    return compact(expanded, mask.reshape(b * d), out_cap)
+    return compact_extend(rows, cands, mask, j0, out_cap)
 
 
 @functools.partial(jax.jit, static_argnames=("ext", "verify_pos", "out_cap"))

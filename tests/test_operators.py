@@ -1,4 +1,5 @@
 """Unit tests for the vectorised operator kernels."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,6 +15,56 @@ def test_compact_packs_front():
     assert int(n) == 4
     np.testing.assert_array_equal(np.asarray(out[:4, 0]), [0, 4, 8, 14])
     assert np.all(np.asarray(out[4:]) == INVALID)
+
+
+def _extension(rows, cands, mask):
+    """The extension's rows in row-major order, by numpy."""
+    r, c = np.nonzero(mask)
+    return np.concatenate([rows[r], cands[r, c][:, None]], axis=1)
+
+
+def _mask(case, rng, b, d):
+    if case == "empty":
+        return np.zeros((b, d), bool)
+    mask = rng.random((b, d)) < 0.02
+    if case == "hub":   # one row holds most survivors, across every block
+        mask[b // 3] = rng.random(d) < 0.9
+    return mask
+
+
+@pytest.mark.parametrize("case, b, d, out_cap, resume", [
+    ("random", 16, 300, 512, False),   # d off the 128-lane tile
+    ("hub", 32, 384, 1024, False),
+    ("empty", 8, 256, 64, False),
+    ("beyond", 16, 256, 64, False),    # true n past out_cap, first rows only
+    ("resumed", 32, 384, 100, True),   # j0 = 0, W, 2W, … covers every row once
+    ("hub-resumed", 16, 256, 48, True),
+])
+def test_compact_extend_matches_numpy(case, b, d, out_cap, resume):
+    rng = np.random.default_rng(len(case) * b)
+    rows = rng.integers(0, 1 << 20, (b, 3)).astype(np.int32)
+    cands = rng.integers(0, 1 << 20, (b, d)).astype(np.int32)
+    mask = _mask(case.split("-")[0], rng, b, d)
+    want = _extension(rows, cands, mask)
+    step = jax.jit(ops.compact_extend, static_argnames="out_cap")
+    parts, j0 = [], 0
+    while True:
+        out, n = step(jnp.asarray(rows), jnp.asarray(cands), jnp.asarray(mask),
+                      jnp.int32(j0), out_cap=out_cap)
+        out = np.asarray(out)
+        assert int(n) == len(want)
+        kept = min(max(len(want) - j0, 0), out_cap)
+        assert np.all(out[kept:] == INVALID)
+        parts.append(out[:kept])
+        j0 += out_cap
+        if not resume or j0 >= len(want):
+            break
+    got = np.concatenate(parts)
+    np.testing.assert_array_equal(got, want if resume else want[:out_cap])
+    if case == "beyond":
+        assert len(want) > out_cap
+    if resume:
+        assert len(parts) > 2
 
 
 def test_queue_append_pop_roundtrip():

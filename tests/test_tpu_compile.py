@@ -6,19 +6,22 @@ refuses what interpret mode accepts: block shapes off the (8, 128) tiling,
 more VMEM than a kernel may use, a program that does not fit the device. The
 shapes are the one-chip smoke's real size: ``powerlaw_graph(2**16, 8.0)``
 pads adjacency rows to 3712 lanes, batches are 1024 rows of 3 columns and an
-extend intersects 2 or 3 slabs.
+extend intersects 2 or 3 slabs. The unfused extend is also compiled at the
+benchmark graph ``go16``'s widths (3200 lanes, one call 32768 rows wide).
 
 The topology is described inside a module fixture, never at import: only one
 process may hold the TPU library, and every test worker imports this file.
 """
 from __future__ import annotations
 
+import math
 import os
 import re
 
 import pytest
 
 D_PAD, BATCH, K, ROWS = 3712, 1024, 3, 1 << 16
+GO16_D_PAD, GO16_WIDTH = 3200, BATCH * 32  # mean degree 9.87: 32 lanes a row
 
 
 @pytest.fixture(scope="module")
@@ -122,6 +125,40 @@ def test_unfused_extend_step_compiles(spec, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16 << 30, used
+
+
+def _index_counts(hlo: str):
+    """``(op, indices)`` of every gather and scatter in compiled HLO text:
+    the elements of its index operand, less the index vector's axis."""
+    shapes = dict(re.findall(r"%(\S+) = \w+\[([\d,]*)\]", hlo))
+    found = []
+    for op, args, vdim in re.findall(
+            r"\b(gather|scatter)\(%\S+, %([^,)\s]+).*?index_vector_dim=(\d+)",
+            hlo):
+        dims = [int(x) for x in shapes[args].split(",") if x]
+        found.append((op, math.prod(dims) // (dims[int(vdim)]
+                                              if int(vdim) < len(dims) else 1)))
+    return found
+
+
+@pytest.mark.parametrize("k, ext", [(2, (0, 1)), (3, (0, 1, 2))])
+def test_narrow_extend_gathers_no_bdpad_indices(spec, monkeypatch, k, ext):
+    """Both of ``go16``'s unfused extends, as the engine calls them: every
+    gather and scatter runs over the batch's rows or the call's output
+    width, none over ``B·d_pad`` (the expand-and-compact this replaced ran
+    a 3,276,800-index scatter and gather each step)."""
+    from repro.core import operators as ops_mod
+    from repro.kernels.intersect import ops as ik
+
+    monkeypatch.setattr(ik, "_on_tpu", lambda: True)
+
+    hlo = ops_mod.extend_batch.lower(
+        spec((ROWS, GO16_D_PAD)), spec((BATCH, k)), spec(()), ext=ext,
+        lt=(0,), gt=(), out_cap=GO16_WIDTH, j0=spec(()),
+    ).compile().as_text()
+    counts = _index_counts(hlo)
+    assert {op for op, _ in counts} == {"gather", "scatter"}
+    assert max(c for _, c in counts) <= GO16_WIDTH, counts
 
 
 def _small_kernel_calls(spec):
